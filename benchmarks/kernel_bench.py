@@ -31,17 +31,3 @@ def run(report):
             f"(v5e VMEM 16MiB)",
         )
 
-    a = jax.nn.sigmoid(jax.random.normal(ks[0], (2, 512, 256))) * 0.6 + 0.3
-    b = jax.random.normal(ks[1], (2, 512, 256)) * 0.1
-    h0 = jnp.zeros((2, 256))
-    expect = ref.rglru_scan_ref(a, b, h0)
-    for tb in (128, 256):
-        t0 = time.perf_counter()
-        from repro.kernels.rglru_scan import rglru_scan_fwd
-
-        out = rglru_scan_fwd(a, b, h0, t_block=tb, w_block=256, interpret=True)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        err = float(jnp.max(jnp.abs(out - expect)))
-        report(f"kernel_bench/rglru_tb{tb}", dt * 1e6,
-               f"err={err:.1e} vmem={3 * tb * 256 * 4 / 1024:.0f}KiB")

@@ -10,7 +10,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.configs import RunConfig, ShapeConfig, get_config
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import (
@@ -51,7 +50,7 @@ def run(report):
         model = Model(cfg)
         shape = ShapeConfig("bench", seq_len=128, global_batch=4, kind="train")
         run_cfg = RunConfig(total_steps=10)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, _, state_sh, batch_sh = build_train_step(
                 model, run_cfg, mesh, shape
             )
@@ -68,7 +67,7 @@ def run(report):
     # decode step
     cfg = get_config("llama3.2-1b", smoke=True)
     model = Model(cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pshape = ShapeConfig("bench", seq_len=32, global_batch=4, kind="prefill")
         prefill, _, (psh, bsh, csh) = build_prefill_step(model, mesh, pshape, 64)
         dshape = ShapeConfig("bench", seq_len=64, global_batch=4, kind="decode")

@@ -49,22 +49,14 @@ def _decode(arr: np.ndarray, dtype_name: str) -> np.ndarray:
     return arr
 
 
-def _keystr_simple(k) -> str:
-    """``jax.tree_util.keystr(..., simple=True)`` with a jax-0.4.x fallback
-    (the ``simple`` kwarg is newer than the pinned CI jax)."""
-    try:
-        return jax.tree_util.keystr((k,), simple=True)
-    except TypeError:
-        for attr in ("key", "idx", "name"):
-            if hasattr(k, attr):
-                return str(getattr(k, attr))
-        return str(k)
+def _path_key(path) -> str:
+    return jax.tree_util.keystr(path, simple=True, separator="/")
 
 
 def _flatten_with_paths(tree) -> Dict[str, np.ndarray]:
     flat = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = "/".join(_keystr_simple(k) for k in path)
+        key = _path_key(path)
         flat[key] = np.asarray(leaf)
     return flat
 
@@ -167,7 +159,7 @@ def load_checkpoint(
         paths = jax.tree_util.tree_flatten_with_path(like)
         leaves = []
         for path, leaf in paths[0]:
-            key = "/".join(_keystr_simple(k) for k in path)
+            key = _path_key(path)
             if key not in flat:
                 raise KeyError(f"checkpoint missing array {key}")
             arr = flat[key]

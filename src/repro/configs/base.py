@@ -83,10 +83,12 @@ class ModelConfig:
     frontend_tokens: int = 256   # prepended embedding tokens for vlm
     dtype: str = "bfloat16"
     remat: str = "block"         # none | block | full
-    # attention chunking (XLA online-softmax path; Pallas kernel on TPU)
+    # attention blocks (XLA online-softmax path and the Pallas flash kernel)
     q_block: int = 512
     k_block: int = 1024
-    use_pallas: bool = False     # TPU deployment flag (CPU container: False)
+    # train/prefill attention through the Pallas flash kernel (compiled by
+    # Mosaic on a TPU, interpreted elsewhere) instead of the XLA path
+    use_pallas: bool = False
 
     @property
     def resolved_head_dim(self) -> int:

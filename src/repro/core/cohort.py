@@ -44,8 +44,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 
 # --------------------------------------------------------------------------
 # Standalone primitive: bucketed cohort all-reduce (fully manual shard_map)
@@ -101,7 +99,7 @@ def cohort_all_reduce(
     # All mesh axes manual: the body is a pure collective schedule and the
     # value is replicated over every axis it does not reduce.
     spec = P()  # replicated in; replicated out (a true all-reduce)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,),
@@ -115,7 +113,7 @@ def cohort_all_reduce(
 def flat_all_reduce(tree, mesh: Mesh, axes: Sequence[str] = ("pod", "data")):
     """The paper-baseline: one flat psum spanning both fabrics (the analogue
     of every process hammering the global word with rCAS)."""
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda t: jax.tree.map(lambda x: lax.psum(x, tuple(axes)), t),
         mesh=mesh,
         in_specs=(P(),),
@@ -234,7 +232,7 @@ def wrap_step_with_pod_sync(
         metrics = jax.tree.map(lambda m: lax.pmean(m, cfg.pod_axis), metrics)
         return new_state, metrics
 
-    return shard_map(
+    return jax.shard_map(
         lifted,
         mesh=mesh,
         in_specs=(state_pod_spec, batch_spec),

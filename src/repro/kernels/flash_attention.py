@@ -1,16 +1,19 @@
 """Flash attention forward kernel (Pallas TPU).
 
 TPU-native tiling of the online-softmax attention in
-``repro.models.attention.online_attention`` (same contract):
+``repro.models.attention.online_attention``, on head-major operands
+(``kernels.ops.flash_attention`` transposes to and from the models'
+``[B, T, H, d]`` contract):
 
 * grid ``(B, H, nq, nk)`` — the last (innermost) dimension is *sequential*
   ("arbitrary" semantics on TPU): the kernel revisits the same output block
   for each KV block, accumulating running (max, sum, acc) in fp32 VMEM
   scratch and finalising on the last KV step;
 * BlockSpecs stage ``[qb, d]`` query tiles and ``[kb, d]`` KV tiles into VMEM
-  (qb/kb default 512/1024 → the dominant working set is
-  qb·d + kb·d + qb·kb ≈ 0.8 MB at d=128 in bf16 — comfortably inside the
-  ~16 MB v5e VMEM, leaving room for double buffering);
+  as the last two block dims, which is what Mosaic tiles (a head dim of 1
+  in second-to-last position is refused); qb/kb default 512/1024 → the
+  dominant working set is qb·d + kb·d + qb·kb ≈ 0.8 MB at d=128 in bf16 —
+  comfortably inside the ~16 MB v5e VMEM, leaving room for double buffering;
 * matmul tiles are MXU-aligned (qb, kb, d multiples of 128; d=64 heads still
   map acceptably);
 * GQA is handled by indexing the KV head as ``h // (H // K)`` in the
@@ -19,10 +22,8 @@ TPU-native tiling of the online-softmax attention in
   position tiles, so padded cells never contribute.
 
 Validated in ``interpret=True`` mode against the pure-jnp oracle
-(``kernels/ref.py``) across shape/dtype sweeps (tests/test_kernels.py);
-this CPU container cannot compile Mosaic, so the XLA path remains the
-dry-run/roofline implementation and this kernel is the TPU deployment path
-(``ModelConfig.use_pallas``).
+(``kernels/ref.py``) across shape/dtype sweeps (tests/test_kernels.py), and
+compiled by Mosaic for a described v5e chip in tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
@@ -35,9 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# renamed CompilerParams (new) <- TPUCompilerParams (jax 0.4.x)
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 _NEG_INF = -1e30
 
@@ -56,9 +54,9 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                      # [qb, dk]
-    k = k_ref[0, :, 0, :]                      # [kb, dk]
-    v = v_ref[0, :, 0, :]                      # [kb, dv]
+    q = q_ref[0, 0]                             # [qb, dk]
+    k = k_ref[0, 0]                             # [kb, dk]
+    v = v_ref[0, 0]                             # [kb, dv]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -88,23 +86,24 @@ def _flash_kernel(
     @pl.when(ki == nk - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
-    q: jnp.ndarray,   # [B, Tq, H, dk]
-    k: jnp.ndarray,   # [B, Tk, K, dk]
-    v: jnp.ndarray,   # [B, Tk, K, dv]
+    q: jnp.ndarray,   # [B, H, Tq, dk]
+    k: jnp.ndarray,   # [B, K, Tk, dk]
+    v: jnp.ndarray,   # [B, K, Tk, dv]
     *,
     causal: bool = True,
     window: int = 0,
     q_block: int = 512,
     k_block: int = 1024,
     scale: Optional[float] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
-    B, Tq, H, dk = q.shape
-    _, Tk, K, dv = v.shape
+    """Head-major flash attention forward → ``[B, H, Tq, dv]``."""
+    B, H, Tq, dk = q.shape
+    _, K, Tk, dv = v.shape
     assert H % K == 0, (H, K)
     G = H // K
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
@@ -114,12 +113,12 @@ def flash_attention_fwd(
     pq = (-Tq) % qb
     pk = (-Tk) % kb
     if pq:
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, 0)))
     if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    nq = q.shape[1] // qb
-    nk = k.shape[1] // kb
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0)))
+    nq = q.shape[2] // qb
+    nk = k.shape[2] // kb
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, window=window, scale=scale,
@@ -129,20 +128,20 @@ def flash_attention_fwd(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, qb, 1, dk), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, kb, 1, dk), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, kb, 1, dv), lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((1, 1, qb, dk), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, kb, dk), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, kb, dv), lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, qb, 1, dv), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * qb, H, dv), v.dtype),
+        out_specs=pl.BlockSpec((1, 1, qb, dv), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * qb, dv), v.dtype),
         scratch_shapes=[
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, dv), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Tq]
+    return out[:, :, :Tq]

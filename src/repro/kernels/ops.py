@@ -1,30 +1,23 @@
 """Jit'd public wrappers for the Pallas kernels, with autodiff.
 
-``flash_attention`` and ``rglru_scan`` run the Pallas forward kernel and use
-a recompute-based backward (``jax.custom_vjp`` around the jnp oracle's vjp) —
-the standard flash trade: no O(T²) residuals, backward recomputes tiles.
+``flash_attention`` runs the Pallas forward kernel and uses a
+recompute-based backward (``jax.custom_vjp`` around the jnp oracle's vjp).
+That backward materialises the quadratic ``[B, H, T, T]`` scores; a blocked
+backward is still to be written.
 
-On this CPU container kernels execute in ``interpret=True`` mode; on TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or pass ``interpret=False``) to compile with
-Mosaic.
+Kernels compile with Mosaic when the default backend is a TPU and run in
+Pallas interpret mode on any other backend (the CPU test suite).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_fwd
-from .rglru_scan import rglru_scan_fwd
-
-
-def _default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 @functools.partial(
@@ -34,11 +27,15 @@ def flash_attention(
     q, k, v, causal: bool = True, window: int = 0,
     q_block: int = 512, k_block: int = 1024, scale: Optional[float] = None,
 ):
-    return flash_attention_fwd(
-        q, k, v, causal=causal, window=window,
+    """``[B, T, H, d]`` contract of ``models.attention.online_attention``;
+    the kernel itself runs head-major."""
+    out = flash_attention_fwd(
+        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+        causal=causal, window=window,
         q_block=q_block, k_block=k_block, scale=scale,
-        interpret=_default_interpret(),
+        interpret=jax.default_backend() != "tpu",
     )
+    return out.swapaxes(1, 2)
 
 
 def _fa_fwd(q, k, v, causal, window, q_block, k_block, scale):
@@ -58,21 +55,3 @@ def _fa_bwd(causal, window, q_block, k_block, scale, res, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
-
-
-@jax.custom_vjp
-def rglru_scan(a, b, h0):
-    return rglru_scan_fwd(a, b, h0, interpret=_default_interpret())
-
-
-def _rg_fwd(a, b, h0):
-    return rglru_scan(a, b, h0), (a, b, h0)
-
-
-def _rg_bwd(res, g):
-    a, b, h0 = res
-    _, vjp = jax.vjp(ref.rglru_scan_ref, a, b, h0)
-    return vjp(g)
-
-
-rglru_scan.defvjp(_rg_fwd, _rg_bwd)

@@ -27,7 +27,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.configs import ARCHS, SHAPES, get_config, shape_cells, RunConfig
 from repro.launch import hloparse
 from repro.launch.mesh import make_production_mesh
@@ -144,7 +143,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, sync_mode: str,
     if run.microbatches > mb_cap:
         run = dataclasses.replace(run, microbatches=mb_cap)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step, state_shapes, state_sh, batch_sh = build_train_step(
                 model, run, mesh, shape

@@ -7,12 +7,11 @@ initialisation; tests must see one device).
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax
 from jax.sharding import Mesh
-
-from ..compat import make_mesh as _compat_make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -20,9 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False,
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
+    n = math.prod(shape)
     if devices is None:
         devices = jax.devices()[:n]
     if len(devices) < n:
@@ -31,10 +28,16 @@ def make_production_mesh(*, multi_pod: bool = False,
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             "=512 before importing jax"
         )
-    return _compat_make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices: Optional[Sequence] = None) -> Mesh:
-    """Arbitrary mesh for tests/examples (CPU-scale)."""
-    return _compat_make_mesh(shape, axes, devices=devices)
+    """Mesh over the first ``prod(shape)`` devices, every axis ``Auto``."""
+    if devices is None:
+        devices = jax.devices()[:math.prod(shape)]
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )

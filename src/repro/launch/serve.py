@@ -1,4 +1,4 @@
-"""Batched serving driver: prefill a prompt batch, then greedy decode.
+"""Batched serving driver: prefill a prompt batch, then decode (greedy by default).
 
 ``serve`` is the library entry (used by examples/serve_batch.py and the e2e
 tests); ``main`` is the CLI.  Batching model: requests accumulate into fixed
@@ -19,6 +19,7 @@ zero simulated RDMA operations on its own admission path.  Off by default
 from __future__ import annotations
 
 import argparse
+import functools
 import threading
 import time
 from typing import Dict, Optional
@@ -27,12 +28,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import set_mesh
 from ..configs import ShapeConfig, get_config
 from ..coord import CoordinationService, LeaseMode, RecoverableClient
 from ..core import Overloaded
 from ..data import SyntheticLMDataset
 from ..models import Model, input_specs
+from .compile_cache import use_compilation_cache
 from .mesh import make_mesh
 from .steps import build_decode_step, build_prefill_step
 
@@ -333,6 +334,18 @@ class BatchAdmission:
         }
 
 
+@functools.partial(jax.jit, static_argnums=3)
+def _next_token(logits, key, step, greedy: bool):
+    """Sample ``[B, 1]`` tokens from the last position's logits, and say
+    whether every logit of the step was finite."""
+    last = logits[:, -1]
+    if greedy:
+        tok = jnp.argmax(last, axis=-1)
+    else:
+        tok = jax.random.categorical(jax.random.fold_in(key, step), last)
+    return tok[:, None].astype(jnp.int32), jnp.all(jnp.isfinite(logits))
+
+
 def serve(
     arch: str,
     *,
@@ -363,53 +376,61 @@ def serve(
     pshape = ShapeConfig("serve", seq_len=prompt_len, global_batch=batch,
                          kind="prefill")
 
-    with set_mesh(mesh):
-        prefill_fn, _, (param_sh, batch_sh, cache_sh) = build_prefill_step(
+    with jax.set_mesh(mesh):
+        prefill_fn, _, (param_sh, batch_sh, _) = build_prefill_step(
             model, mesh, pshape, max_len
         )
         dshape = ShapeConfig("serve", seq_len=max_len, global_batch=batch,
                              kind="decode")
         decode_fn, _, _ = build_decode_step(model, mesh, dshape, max_len)
 
-        params = jax.device_put(model.init(jax.random.PRNGKey(seed)), param_sh)
+        # Parameters are created already sharded: no device ever holds an
+        # unsharded copy.
+        params = jax.jit(model.init, out_shardings=param_sh)(
+            jax.random.PRNGKey(seed))
         prompts = input_specs(cfg, pshape, concrete=True,
                               rng=jax.random.PRNGKey(seed + 1))
         prompts = jax.device_put(prompts, batch_sh)
+        sample_key = jax.random.PRNGKey(seed + 2)
 
-        # Admit only now: model build is per-call setup, and the slot TTL
+        # Compile every program of the batch before admission: the slot TTL
         # must budget batch *execution*, not JIT compilation (a compile
-        # outlasting the TTL would expire a healthy batch's lease and let the
-        # slot be double-granted).  The first prefill call still compiles, so
-        # warm it before taking the slot when the jitted fn supports AOT.
-        if admission:
-            try:
-                prefill_fn.lower(params, prompts).compile()
-            except (AttributeError, TypeError):
-                pass  # not a jitted callable: compile lands inside the lease
+        # outlasting the TTL would expire a healthy batch's lease and let
+        # the slot be double-granted).  The loop calls these executables
+        # directly, so an input that does not match them raises instead of
+        # compiling again inside the lease and the timed region.
+        t_c = time.perf_counter()
+        prefill_exe = prefill_fn.lower(params, prompts).compile()
+        logits_info, cache_info = prefill_exe.out_info
+        sample_first = _next_token.lower(
+            logits_info, sample_key, 0, greedy).compile()
+        decode_exe = decode_fn.lower(
+            params, cache_info, sample_first.out_info[0]).compile()
+        sample_next = _next_token.lower(
+            decode_exe.out_info[0], sample_key, 0, greedy).compile()
+        compile_s = time.perf_counter() - t_c
+
         slot = admission.admit(timeout=admission_ttl) if admission else None
         try:
-            t0 = time.time()
-            logits, caches = prefill_fn(params, prompts)
-            tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-            prefill_s = time.time() - t0
+            t0 = time.perf_counter()
+            logits, caches = prefill_exe(params, prompts)
+            jax.block_until_ready((logits, caches))
+            prefill_s = time.perf_counter() - t0
+            tok, finite = sample_first(logits, sample_key, 0)
+            finite_steps = [finite]
             if admission:
                 slot = admission.keepalive(slot)  # prefill done; extend
 
             generated = [np.asarray(tok)]
-            t1 = time.time()
+            t1 = time.perf_counter()
             for step in range(gen_len - 1):
-                logits, caches = decode_fn(params, caches, tok)
-                if greedy:
-                    tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-                else:
-                    tok = jax.random.categorical(
-                        jax.random.PRNGKey(int(time.time() * 1e6) % 2**31),
-                        logits[:, -1],
-                    )[:, None].astype(jnp.int32)
+                logits, caches = decode_exe(params, caches, tok)
+                tok, finite = sample_next(logits, sample_key, step + 1)
+                finite_steps.append(finite)
                 generated.append(np.asarray(tok))
                 if admission and step % 8 == 7:
                     slot = admission.keepalive(slot)  # TTL covers ~8 steps
-            decode_s = time.time() - t1
+            decode_s = time.perf_counter() - t1
         finally:
             # Release on *every* exit: an exception mid-batch must not hold
             # the slot hostage for the rest of its TTL.
@@ -419,6 +440,8 @@ def serve(
     tokens = np.concatenate(generated, axis=1)
     out = {
         "tokens": tokens,
+        "logits_finite": bool(np.all(jax.device_get(finite_steps))),
+        "compile_seconds": compile_s,
         "prefill_seconds": prefill_s,
         "decode_seconds_per_token": decode_s / max(gen_len - 1, 1),
         "throughput_tok_s": tokens.size / max(decode_s + prefill_s, 1e-9),
@@ -433,15 +456,21 @@ def serve(
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--admission-slots", type=int, default=0,
                     help="admit the batch through the sharded lock table")
     args = ap.parse_args()
-    out = serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
-                gen_len=args.gen, admission_slots=args.admission_slots)
+    use_compilation_cache()
+    out = serve(args.arch, smoke=args.smoke, batch=args.batch,
+                prompt_len=args.prompt_len, gen_len=args.gen, seed=args.seed,
+                admission_slots=args.admission_slots)
     print(f"[serve] generated {out['tokens'].shape} tokens; "
+          f"compile {out['compile_seconds']:.2f}s, "
           f"prefill {out['prefill_seconds']:.2f}s, "
           f"{out['decode_seconds_per_token'] * 1e3:.1f} ms/token, "
           f"{out['throughput_tok_s']:.1f} tok/s")

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import ModelConfig, RunConfig, ShapeConfig
 from ..models import Model
 from ..models.layers import activation_rules
@@ -79,7 +78,7 @@ def _int8_pod_mean(grads_p, ef_p, mesh: Mesh):
     # quantize), and inputs are replicated over data/model, so claiming every
     # axis is equivalent — and partial-manual islands trip XLA partitioner
     # bugs on older jax (same reason as the MoE island, see models/moe.py).
-    fn = shard_map(
+    fn = jax.shard_map(
         exchange,
         mesh=mesh,
         in_specs=(P("pod"), P("pod")),
@@ -242,9 +241,7 @@ def build_train_step(
     """Returns (jitted step, state shapes, state shardings, batch shardings)."""
     cfg = model.cfg
     multi_pod = "pod" in mesh.shape
-    npods = mesh.shape.get("pod", 1) if hasattr(mesh.shape, "get") else (
-        dict(mesh.shape).get("pod", 1)
-    )
+    npods = mesh.shape.get("pod", 1)
     state_shapes, state_pspecs = train_state_specs(model, run, npods)
     mode = run.sync_mode if multi_pod else "flat"
 

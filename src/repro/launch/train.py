@@ -15,6 +15,7 @@ Fault-tolerance wiring:
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 from typing import Dict, Optional
 
@@ -23,11 +24,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint import CheckpointManager, load_checkpoint
-from ..compat import set_mesh
 from ..configs import RunConfig, SHAPES, ShapeConfig, get_config
 from ..coord import CoordinationService
 from ..data import SyntheticLMDataset, make_batch_iterator
 from ..models import Model
+from .compile_cache import use_compilation_cache
 from .mesh import make_mesh
 from .steps import build_train_step, init_train_state
 
@@ -57,29 +58,26 @@ def train(
         run.checkpoint_dir, every=run.checkpoint_every, svc=svc, host=0
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, state_shapes, state_sh, batch_sh = build_train_step(
             model, run, mesh, shape
         )
         start_step = 0
+        state = None
         if resume:
             try:
-                host_state, start_step, extra = load_checkpoint(
+                state, start_step, _ = load_checkpoint(
                     run.checkpoint_dir, state_shapes, shardings=state_sh
                 )
-                state = host_state
                 print(f"[train] resumed from step {start_step}")
             except FileNotFoundError:
-                state = jax.device_put(
-                    init_train_state(model, run, jax.random.PRNGKey(run.seed),
-                                     npods),
-                    state_sh,
-                )
-        else:
-            state = jax.device_put(
-                init_train_state(model, run, jax.random.PRNGKey(run.seed), npods),
-                state_sh,
-            )
+                pass
+        if state is None:
+            # Created already sharded: no device ever holds an unsharded
+            # copy of the parameters or the optimizer state.
+            init = functools.partial(init_train_state, model, run, npods=npods)
+            state = jax.jit(init, out_shardings=state_sh)(
+                jax.random.PRNGKey(run.seed))
 
         data = SyntheticLMDataset(cfg, shape, seed=run.seed)
         it = make_batch_iterator(data, start_step=start_step)
@@ -115,6 +113,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--sync-mode", default="flat")
     args = ap.parse_args()
+    use_compilation_cache()
     run = RunConfig(
         total_steps=args.steps,
         checkpoint_dir=args.ckpt_dir,

@@ -23,7 +23,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import get_abstract_mesh, shard_map
 from ..configs.base import ModelConfig, MoEConfig
 from .layers import ashard, mlp, mlp_spec
 from .specs import ParamSpec
@@ -277,7 +276,7 @@ def _manual_ep_moe(p, x: jnp.ndarray, cfg: ModelConfig):
     # rows (flat multi-pod mode); the EP group stays within a pod and expert
     # grads psum over `pod` at the island boundary (weights are replicated
     # over `pod` in their specs).
-    mesh_axes = tuple(get_abstract_mesh().axis_names)
+    mesh_axes = tuple(jax.sharding.get_abstract_mesh().axis_names)
     batch_axes = ("pod", "data") if "pod" in mesh_axes else ("data",)
     # EP group: all chips of a pod when E divides data*model (deepseek-v3:
     # one expert per chip, weights never move); else the model axis with
@@ -287,7 +286,7 @@ def _manual_ep_moe(p, x: jnp.ndarray, cfg: ModelConfig):
     fsdp_gather = not two_d
     wspec = P(("data", "model")) if two_d else P("model", "data")
     body = _manual_ep_body(cfg, ep_axes, fsdp_gather, batch_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         in_specs=(P(batch_axes, None, None), P(), wspec, wspec),
         out_specs=(P(batch_axes, None, None), P()),
@@ -310,7 +309,7 @@ def moe_ffn(
         # decode (T=1) and ragged T fall back to the GSPMD scatter path
         # (small tensors — the expensive case the island exists for is the
         # capacity-buffer einsum at training/prefill scale).
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         msize = dict(mesh.shape).get("model", 1) if mesh is not None else 1
         if T % max(msize, 1) != 0 or msize <= 1:
             use_island = False

@@ -25,7 +25,6 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import get_abstract_mesh, shard_map
 from ..configs.base import ModelConfig, XLSTMConfig
 from .layers import ashard, rmsnorm, rmsnorm_spec
 from .specs import ParamSpec
@@ -319,10 +318,10 @@ def slstm_block(p, x: jnp.ndarray, cfg: ModelConfig,
     if _ACT_RULES:  # distributed: fully-manual island, batch over data(+pod)
         from jax.sharding import PartitionSpec as P
 
-        mesh_axes = tuple(get_abstract_mesh().axis_names)
+        mesh_axes = tuple(jax.sharding.get_abstract_mesh().axis_names)
         b_axes = ("pod", "data") if "pod" in mesh_axes else ("data",)
         bspec = P(b_axes)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda r, w, s: _slstm_scan_local(r, w, s, cfg),
             in_specs=(P(), bspec, jax.tree.map(lambda _: bspec, state)),
             out_specs=(bspec, jax.tree.map(lambda _: bspec, state)),

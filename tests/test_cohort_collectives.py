@@ -21,7 +21,8 @@ def test_cohort_all_reduce_equals_flat(multidevice):
     out = multidevice(
         """
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh, set_mesh
+from jax import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core import cohort_all_reduce, flat_all_reduce
 mesh = make_mesh((2,2,2), ('pod','data','model'))
 tree = {'w': jnp.arange(24, dtype=jnp.float32).reshape(4,6),
@@ -47,7 +48,8 @@ def test_int8_error_feedback_converges(multidevice):
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, set_mesh, shard_map
+from jax import set_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.cohort import pod_sync_grads, SyncConfig
 mesh = make_mesh((2,2,2), ('pod','data','model'))
 cfg = SyncConfig(mode='sync', compress_int8=True)

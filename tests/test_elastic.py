@@ -9,7 +9,7 @@ def test_remesh_restore_preserves_state(multidevice, tmp_path):
     out = multidevice(
         f"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_config, ShapeConfig, RunConfig
 from repro.models import Model, input_specs
 from repro.launch.mesh import make_mesh

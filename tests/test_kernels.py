@@ -51,35 +51,6 @@ def test_flash_attention_grads_match_ref():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-@pytest.mark.parametrize("B,T,W,tb,wb", [
-    (2, 100, 48, 32, 16),
-    (1, 64, 128, 64, 128),
-    (3, 33, 20, 16, 8),
-])
-def test_rglru_kernel_matches_ref(B, T, W, tb, wb):
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    a = (jax.nn.sigmoid(jax.random.normal(ks[0], (B, T, W))) * 0.6 + 0.3).astype(
-        jnp.float32
-    )
-    b = (jax.random.normal(ks[1], (B, T, W)) * 0.1).astype(jnp.float32)
-    h0 = (jax.random.normal(ks[2], (B, W)) * 0.1).astype(jnp.float32)
-    from repro.kernels.rglru_scan import rglru_scan_fwd
-
-    out = rglru_scan_fwd(a, b, h0, t_block=tb, w_block=wb, interpret=True)
-    expect = ref.rglru_scan_ref(a, b, h0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5)
-
-
-def test_rglru_kernel_grad_matches_ref():
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    a = jax.nn.sigmoid(jax.random.normal(ks[0], (1, 32, 16))) * 0.5 + 0.3
-    b = jax.random.normal(ks[1], (1, 32, 16)) * 0.1
-    h0 = jax.random.normal(ks[2], (1, 16)) * 0.1
-    gk = jax.grad(lambda a: ops.rglru_scan(a, b, h0).sum())(a)
-    gr = jax.grad(lambda a: ref.rglru_scan_ref(a, b, h0).sum())(a)
-    np.testing.assert_allclose(np.asarray(gk), np.asarray(gr), atol=1e-5)
-
-
 def test_online_attention_equals_kernel_contract():
     """The XLA online-softmax path (the dry-run implementation) and the
     Pallas kernel implement the same function."""

@@ -1,11 +1,12 @@
 """End-to-end behaviour: loss decreases, checkpoints resume deterministically,
 serving generates; multi-device training equivalences run in subprocesses."""
 
+import jax
 import numpy as np
 import pytest
 
 from repro.configs import RunConfig, ShapeConfig
-from repro.launch.serve import serve
+from repro.launch.serve import BatchAdmission, serve
 from repro.launch.train import train
 
 
@@ -49,6 +50,34 @@ def test_serving_generates_tokens():
     assert out["tokens"].dtype.kind == "i"
 
 
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+def test_serving_compiles_nothing_after_admission(greedy):
+    """Every program of the batch compiles before the slot is taken: a
+    compile inside the lease would be charged to the slot TTL and to the
+    prefill/decode clocks."""
+    compiles = []
+
+    def on_event(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(admitted[0])
+
+    class Admission(BatchAdmission):
+        def admit(self, *a, **k):
+            admitted[0] = True
+            return super().admit(*a, **k)
+
+    admitted = [False]
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        out = serve("llama3.2-1b", smoke=True, batch=2, prompt_len=16,
+                    gen_len=10, greedy=greedy, admission=Admission(num_slots=1))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert out["tokens"].shape == (2, 10) and out["logits_finite"]
+    assert admitted[0] and compiles.count(False) >= 2  # prefill, decode
+    assert compiles.count(True) == 0
+
+
 def test_moe_arch_trains(tmp_path):
     run = RunConfig(learning_rate=1e-3, warmup_steps=2, total_steps=20,
                     checkpoint_every=1000, checkpoint_dir=str(tmp_path))
@@ -69,7 +98,7 @@ def test_sync_equals_flat_on_multipod_mesh(multidevice):
     out = multidevice(
         """
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_config, ShapeConfig, RunConfig
 from repro.models import Model, input_specs
 from repro.launch.mesh import make_mesh
@@ -105,7 +134,7 @@ def test_int8_compressed_sync_close_to_exact(multidevice):
     out = multidevice(
         """
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_config, ShapeConfig, RunConfig
 from repro.models import Model, input_specs
 from repro.launch.mesh import make_mesh
@@ -139,7 +168,7 @@ def test_microbatched_grads_match_full_batch(multidevice):
     out = multidevice(
         """
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import get_config, ShapeConfig, RunConfig
 from repro.models import Model, input_specs
 from repro.launch.mesh import make_mesh

@@ -1,0 +1,9 @@
+"""decode_step_ms: device time of one call of the decode program, mean
+over the traced calls (device trace, module events)."""
+
+from bench.stats import module_durations
+
+
+def read(rec):
+    d = module_durations(rec, "decode")
+    return sum(d) / len(d) * 1e3 if d else None

@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper table/claim.
+"""Benchmark harness — one module per paper table/claim, on the host.
 
 Prints ``name,us_per_call,derived`` CSV.  Modules:
 
@@ -6,8 +6,8 @@ Prints ``name,us_per_call,derived`` CSV.  Modules:
   lock_compare  — throughput/fairness vs naive/RPC/filter  (paper §1, §3, §4)
   lock_table_bench — sharded table: scaling, fairness, hot-path fast paths
   collectives   — cohort vs flat DCN traffic               (TPU adaptation)
-  step_bench    — end-to-end step times (CPU, smoke configs)
-  kernel_bench  — Pallas kernels: tiles + correctness
+
+The model's steps and kernels are timed on the chip by ``bench/run.py``.
 
 ``--json OUT`` additionally writes each module's results to
 ``OUT/BENCH_<name>.json`` (default OUT: the repo root), the machine-readable
@@ -34,12 +34,10 @@ def main() -> None:
     args = ap.parse_args()
     rows = []
 
-    from . import (collectives, kernel_bench, lock_compare, lock_ops,
-                   lock_table_bench, step_bench)
+    from . import collectives, lock_compare, lock_ops, lock_table_bench
 
     failures = []
-    for mod in (lock_ops, lock_compare, lock_table_bench, collectives,
-                step_bench, kernel_bench):
+    for mod in (lock_ops, lock_compare, lock_table_bench, collectives):
         mod_rows = []
 
         def report(name, us_per_call, derived="", _rows=mod_rows):

@@ -139,6 +139,11 @@ class OpCounts:
     def local_ops(self) -> int:
         return self.local_read + self.local_write + self.local_cas
 
+    def local_and_rdma(self) -> tuple:
+        """``(local_ops, rdma_ops)`` in one call, for spans on hot paths."""
+        return (self.local_read + self.local_write + self.local_cas,
+                self.remote_read + self.remote_write + self.remote_cas)
+
     def as_tuple(self) -> tuple:
         """O(1) allocation-light snapshot for per-op accounting hot paths."""
         return (

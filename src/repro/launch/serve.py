@@ -21,13 +21,13 @@ from __future__ import annotations
 import argparse
 import functools
 import threading
-import time
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs import ShapeConfig, get_config
 from ..coord import CoordinationService, LeaseMode, RecoverableClient
 from ..core import Overloaded
@@ -70,6 +70,14 @@ class BatchAdmission:
     batch (same slot, same fencing token) instead of waiting out the TTL or
     double-granting capacity.  Anonymous admissions (no ``worker``) keep the
     bare fast path.
+
+    **Spans**: :meth:`admit`, :meth:`keepalive` and :meth:`complete` each
+    write an ``admission.*`` span through :func:`repro.obs.start` and
+    :func:`~repro.obs.end` (the lean form: they run cold between device
+    steps) that carries the slot ``key``, the fencing ``token`` and the
+    calling thread's lock-table operations over the call (``local_ops``,
+    ``rdma_ops``, from its :class:`~repro.core.OpCounts`).  An admission
+    that fails records a span with ``key`` and ``token`` None.
     """
 
     def __init__(self, num_slots: int = 4, ttl: float = 30.0,
@@ -185,6 +193,20 @@ class BatchAdmission:
         With a ``worker`` name the admission is ledgered (see
         :meth:`recover`); anonymous admissions take the bare path.
         """
+        c = self._proc().counts
+        local, rdma = c.local_and_rdma()
+        opened, lease = obs.start("admission.admit"), None
+        try:
+            lease = self._admit(timeout, worker, deadline)
+            return lease
+        finally:
+            local1, rdma1 = c.local_and_rdma()
+            failed = lease is None
+            obs.end(opened, key=None if failed else lease.key,
+                    token=None if failed else lease.token,
+                    local_ops=local1 - local, rdma_ops=rdma1 - rdma)
+
+    def _admit(self, timeout, worker, deadline):
         clock, sleep = self.svc.table.clock, self.svc.table.sleep
         if timeout is not None:
             tdl = clock() + timeout
@@ -270,16 +292,25 @@ class BatchAdmission:
         simulated RDMA operations (``stats()['fast_renews']`` counts the
         fast-path hits; ``local_rdma_ops`` stays 0).
         """
-        if worker is not None:
-            renewed = self._worker(worker).renew(lease)
-        else:
-            # Anonymous keepalives ride the per-thread async pipeline
-            # (PR 10): home renewals resolve inline on the same zero-RDMA
-            # fast path; remote ones ride the next flush as one
-            # witness-CAS WR sharing a doorbell with queued work.
-            pl = self._pipe()
-            renewed = pl.sync(pl.renew(lease))
-            self.svc.note_renewed(self._proc(), lease, renewed)
+        p = self._proc()
+        c = p.counts
+        local, rdma = c.local_and_rdma()
+        opened = obs.start("admission.keepalive")
+        try:
+            if worker is not None:
+                renewed = self._worker(worker).renew(lease)
+            else:
+                # Anonymous keepalives ride the per-thread async pipeline
+                # (PR 10): home renewals resolve inline on the same
+                # zero-RDMA fast path; remote ones ride the next flush as
+                # one witness-CAS WR sharing a doorbell with queued work.
+                pl = self._pipe()
+                renewed = pl.sync(pl.renew(lease))
+                self.svc.note_renewed(p, lease, renewed)
+        finally:
+            local1, rdma1 = c.local_and_rdma()
+            obs.end(opened, key=lease.key, token=lease.token,
+                    local_ops=local1 - local, rdma_ops=rdma1 - rdma)
         if renewed is None:
             raise RuntimeError(
                 f"admission lease on {lease.key} lost (token {lease.token}); "
@@ -288,9 +319,18 @@ class BatchAdmission:
         return renewed
 
     def complete(self, lease, worker: Optional[str] = None) -> bool:
-        if worker is not None:
-            return self._worker(worker).release(lease)
-        return self.svc.release(self._proc(), lease)
+        p = self._proc()
+        c = p.counts
+        local, rdma = c.local_and_rdma()
+        opened = obs.start("admission.complete")
+        try:
+            if worker is not None:
+                return self._worker(worker).release(lease)
+            return self.svc.release(p, lease)
+        finally:
+            local1, rdma1 = c.local_and_rdma()
+            obs.end(opened, key=lease.key, token=lease.token,
+                    local_ops=local1 - local, rdma_ops=rdma1 - rdma)
 
     def stats(self) -> Dict:
         totals = self.svc.class_totals()
@@ -338,12 +378,13 @@ class BatchAdmission:
 def _next_token(logits, key, step, greedy: bool):
     """Sample ``[B, 1]`` tokens from the last position's logits, and say
     whether every logit of the step was finite."""
-    last = logits[:, -1]
-    if greedy:
-        tok = jnp.argmax(last, axis=-1)
-    else:
-        tok = jax.random.categorical(jax.random.fold_in(key, step), last)
-    return tok[:, None].astype(jnp.int32), jnp.all(jnp.isfinite(logits))
+    with jax.named_scope("sample"):
+        last = logits[:, -1]
+        if greedy:
+            tok = jnp.argmax(last, axis=-1)
+        else:
+            tok = jax.random.categorical(jax.random.fold_in(key, step), last)
+        return tok[:, None].astype(jnp.int32), jnp.all(jnp.isfinite(logits))
 
 
 def serve(
@@ -399,38 +440,43 @@ def serve(
         # the slot be double-granted).  The loop calls these executables
         # directly, so an input that does not match them raises instead of
         # compiling again inside the lease and the timed region.
-        t_c = time.perf_counter()
-        prefill_exe = prefill_fn.lower(params, prompts).compile()
-        logits_info, cache_info = prefill_exe.out_info
-        sample_first = _next_token.lower(
-            logits_info, sample_key, 0, greedy).compile()
-        decode_exe = decode_fn.lower(
-            params, cache_info, sample_first.out_info[0]).compile()
-        sample_next = _next_token.lower(
-            decode_exe.out_info[0], sample_key, 0, greedy).compile()
-        compile_s = time.perf_counter() - t_c
+        with obs.span("serve.compile") as compiled:
+            prefill_exe = prefill_fn.lower(params, prompts).compile()
+            logits_info, cache_info = prefill_exe.out_info
+            sample_first = _next_token.lower(
+                logits_info, sample_key, 0, greedy).compile()
+            decode_exe = decode_fn.lower(
+                params, cache_info, sample_first.out_info[0]).compile()
+            sample_next = _next_token.lower(
+                decode_exe.out_info[0], sample_key, 0, greedy).compile()
 
         slot = admission.admit(timeout=admission_ttl) if admission else None
         try:
-            t0 = time.perf_counter()
-            logits, caches = prefill_exe(params, prompts)
-            jax.block_until_ready((logits, caches))
-            prefill_s = time.perf_counter() - t0
-            tok, finite = sample_first(logits, sample_key, 0)
+            with obs.span("serve.prefill") as prefilled:
+                logits, caches = prefill_exe(params, prompts)
+                jax.block_until_ready((logits, caches))
+            with obs.span("serve.sample", step=0):
+                tok, finite = sample_first(logits, sample_key, 0)
             finite_steps = [finite]
             if admission:
                 slot = admission.keepalive(slot)  # prefill done; extend
 
-            generated = [np.asarray(tok)]
-            t1 = time.perf_counter()
+            with obs.span("serve.pull", step=0):
+                generated = [np.asarray(tok)]
+            # Each step: decode, sample, and the pull of its token to the
+            # host, which waits for the device.
+            decoded = []
             for step in range(gen_len - 1):
-                logits, caches = decode_exe(params, caches, tok)
-                tok, finite = sample_next(logits, sample_key, step + 1)
+                with obs.span("serve.decode", step=step + 1) as d:
+                    logits, caches = decode_exe(params, caches, tok)
+                decoded.append(d)
+                with obs.span("serve.sample", step=step + 1):
+                    tok, finite = sample_next(logits, sample_key, step + 1)
                 finite_steps.append(finite)
-                generated.append(np.asarray(tok))
+                with obs.span("serve.pull", step=step + 1) as pulled:
+                    generated.append(np.asarray(tok))
                 if admission and step % 8 == 7:
                     slot = admission.keepalive(slot)  # TTL covers ~8 steps
-            decode_s = time.perf_counter() - t1
         finally:
             # Release on *every* exit: an exception mid-batch must not hold
             # the slot hostage for the rest of its TTL.
@@ -438,10 +484,13 @@ def serve(
                 admission.complete(slot)
 
     tokens = np.concatenate(generated, axis=1)
+    prefill_s = prefilled.seconds
+    decode_s = ((pulled.end_ns - decoded[0].start_ns) * 1e-9
+                if decoded else 0.0)
     out = {
         "tokens": tokens,
         "logits_finite": bool(np.all(jax.device_get(finite_steps))),
-        "compile_seconds": compile_s,
+        "compile_seconds": compiled.seconds,
         "prefill_seconds": prefill_s,
         "decode_seconds_per_token": decode_s / max(gen_len - 1, 1),
         "throughput_tok_s": tokens.size / max(decode_s + prefill_s, 1e-9),

@@ -91,31 +91,35 @@ def _block_apply(cfg: ModelConfig, kind: str, p, x, mode: str,
                  cache=None, max_len: int = 0):
     """Returns (x, new_cache, aux). mode: train | prefill | decode."""
     aux = jnp.float32(0)
+    # Named scopes put "attn" and "ffn" into the ops' metadata, so that a
+    # profile maps device ops back to the block's parts.
     if kind in ("attn", "attn_dense"):
-        h = rmsnorm(p["ln1"], x)
-        if cfg.attention == "mla":
-            if mode == "train":
-                y, new_cache = attn.mla_attention(p["attn"], h, cfg,
-                                                  use_pallas=cfg.use_pallas), cache
-            elif mode == "prefill":
-                y, new_cache = attn.mla_prefill(p["attn"], h, cfg, max_len)
+        with jax.named_scope("attn"):
+            h = rmsnorm(p["ln1"], x)
+            if cfg.attention == "mla":
+                if mode == "train":
+                    y, new_cache = attn.mla_attention(
+                        p["attn"], h, cfg, use_pallas=cfg.use_pallas), cache
+                elif mode == "prefill":
+                    y, new_cache = attn.mla_prefill(p["attn"], h, cfg, max_len)
+                else:
+                    y, new_cache = attn.mla_decode(p["attn"], h, cfg, cache)
             else:
-                y, new_cache = attn.mla_decode(p["attn"], h, cfg, cache)
-        else:
-            if mode == "train":
-                y, new_cache = attn.gqa_attention(p["attn"], h, cfg,
-                                                  use_pallas=cfg.use_pallas), cache
-            elif mode == "prefill":
-                y, new_cache = attn.gqa_prefill(p["attn"], h, cfg, max_len)
+                if mode == "train":
+                    y, new_cache = attn.gqa_attention(
+                        p["attn"], h, cfg, use_pallas=cfg.use_pallas), cache
+                elif mode == "prefill":
+                    y, new_cache = attn.gqa_prefill(p["attn"], h, cfg, max_len)
+                else:
+                    y, new_cache = attn.gqa_decode(p["attn"], h, cfg, cache)
+            x = x + y
+        with jax.named_scope("ffn"):
+            h = rmsnorm(p["ln2"], x)
+            if cfg.moe is not None and kind == "attn":
+                y, aux = moe_mod.moe_ffn(p["ffn"], h, cfg)
             else:
-                y, new_cache = attn.gqa_decode(p["attn"], h, cfg, cache)
-        x = x + y
-        h = rmsnorm(p["ln2"], x)
-        if cfg.moe is not None and kind == "attn":
-            y, aux = moe_mod.moe_ffn(p["ffn"], h, cfg)
-        else:
-            y = mlp(p["ffn"], h, cfg.act)
-        return x + y, new_cache, aux
+                y = mlp(p["ffn"], h, cfg.act)
+            return x + y, new_cache, aux
     if kind == "rec":
         h = rmsnorm(p["ln1"], x)
         if mode == "train":
@@ -126,8 +130,9 @@ def _block_apply(cfg: ModelConfig, kind: str, p, x, mode: str,
         else:
             y, new_cache = rec.rglru_decode(p["rec"], h, cfg, cache)
         x = x + y
-        h = rmsnorm(p["ln2"], x)
-        return x + mlp(p["ffn"], h, cfg.act), new_cache, aux
+        with jax.named_scope("ffn"):
+            h = rmsnorm(p["ln2"], x)
+            return x + mlp(p["ffn"], h, cfg.act), new_cache, aux
     if kind in ("mlstm", "slstm"):
         mod = xl if True else None
         h = rmsnorm(p["ln"], x)
@@ -248,9 +253,10 @@ class Model:
 
     # ----------------------------------------------------------- forward ---
     def _logits(self, params, h):
-        if self.cfg.tie_embeddings:
-            return h @ params["embed"]["table"].T
-        return unembed(params["unembed"], h)
+        with jax.named_scope("head"):
+            if self.cfg.tie_embeddings:
+                return h @ params["embed"]["table"].T
+            return unembed(params["unembed"], h)
 
     def _embed_inputs(self, params, batch) -> jnp.ndarray:
         cfg = self.cfg

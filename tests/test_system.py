@@ -1,10 +1,12 @@
 """End-to-end behaviour: loss decreases, checkpoints resume deterministically,
 serving generates; multi-device training equivalences run in subprocesses."""
 
-import jax
+import time
+
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import RunConfig, ShapeConfig
 from repro.launch.serve import BatchAdmission, serve
 from repro.launch.train import train
@@ -55,26 +57,21 @@ def test_serving_compiles_nothing_after_admission(greedy):
     """Every program of the batch compiles before the slot is taken: a
     compile inside the lease would be charged to the slot TTL and to the
     prefill/decode clocks."""
-    compiles = []
-
-    def on_event(event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiles.append(admitted[0])
-
     class Admission(BatchAdmission):
         def admit(self, *a, **k):
-            admitted[0] = True
+            admitted.append(time.perf_counter_ns())
             return super().admit(*a, **k)
 
-    admitted = [False]
-    jax.monitoring.register_event_duration_secs_listener(on_event)
-    try:
-        out = serve("llama3.2-1b", smoke=True, batch=2, prompt_len=16,
-                    gen_len=10, greedy=greedy, admission=Admission(num_slots=1))
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on_event)
+    admitted = []
+    t = time.perf_counter_ns()
+    out = serve("llama3.2-1b", smoke=True, batch=2, prompt_len=16,
+                gen_len=10, greedy=greedy, admission=Admission(num_slots=1))
+    # Each compile's record, made as the compile ends: before the admission
+    # or after it.
+    compiles = [c.end_ns > admitted[0]
+                for c in obs.spans(obs.COMPILE_SPAN, since_ns=t)]
     assert out["tokens"].shape == (2, 10) and out["logits_finite"]
-    assert admitted[0] and compiles.count(False) >= 2  # prefill, decode
+    assert admitted and compiles.count(False) >= 2  # prefill, decode
     assert compiles.count(True) == 0
 
 

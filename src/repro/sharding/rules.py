@@ -21,6 +21,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig, ShapeConfig
+from ..models.attention import kv_model_dim
 from ..models.specs import pspec_tree, sharding_tree
 
 __all__ = [
@@ -110,13 +111,10 @@ def batch_pspec(cfg: ModelConfig, shape: ShapeConfig, batch_axes=("data",)) -> D
 def _cache_leaf_pspec(leaf_shape, batch_axes, model_size: int = 0) -> P:
     """Caches: dim0 = batch → data. Head-ful leaves get model on the head dim.
 
-    KVCache k/v [B, S, K, hd]: shard K over `model` when divisible, else the
-    head-dim hd — GQA models with K < |model| would otherwise replicate the
-    whole cache across the model axis (measured 34 GB/chip on llama3-8b
-    decode_32k vs 2.2 GB sharded)."""
+    KVCache k/v [B, S, K, hd]: ``model`` on K when it divides, else on hd
+    (`kv_model_dim`, which decode attention follows too)."""
     if len(leaf_shape) == 4:
-        if model_size and leaf_shape[2] % model_size != 0 \
-                and leaf_shape[3] % model_size == 0:
+        if kv_model_dim(leaf_shape[2], leaf_shape[3], model_size) == 3:
             return P(batch_axes, None, None, "model")
         return P(batch_axes, None, "model", None)
     if len(leaf_shape) == 3 and model_size and leaf_shape[1] >= 1024 \

@@ -1,15 +1,18 @@
 """Bring-up guards for the TPU path, at no chip time.
 
 * Compiles for a described (not attached) v5e chip: the Pallas flash kernel
-  at the head dims the models use, and llama3.2-1b's full-width prefill and
-  decode steps.  The topology is described inside a fixture, never while a
-  module is imported, so every pytest-xdist worker collects the same tests.
+  at the head dims the models use, llama3.2-1b's full-width prefill and
+  decode steps, and GLM-4-9B's decode step at the long-context shape.  The
+  topology is described inside a fixture, never while a module is
+  imported, so every pytest-xdist worker collects the same tests.
 * ``chip_smoke.py``'s phase functions at the smoke config on the CPU; only
   its device check, which refuses anything but a TPU, is left out.
 * Where the persistent compilation cache goes.
 """
 
+import math
 import os
+import re
 import subprocess
 import sys
 
@@ -100,6 +103,31 @@ def test_llama_decode_compiles_for_v5e(llama_serving):
         ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_glm4_longctx_decode_never_widens_cache(topo):
+    """GLM-4-9B (32 query heads over 2 KV heads, head 128, every published
+    width; two layers to keep the compile short) at the longctx cell's
+    decode shape, batch 4 over a 4352-slot cache, on one chip: decode
+    attention reads the [B, S, K, d] cache as stored, so no [B, S, H, d]
+    array exists and the step's temporaries stay small (widened: 367 MB)."""
+    model = Model(get_config("glm4-9b").with_overrides(num_layers=2))
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    batch, max_len = 4, 4352
+    shape = ShapeConfig("serve", max_len, batch, "decode")
+    with jax.set_mesh(mesh):
+        step, cache_spec, (param_sh, cache_sh, tok_sh) = build_decode_step(
+            model, mesh, shape, max_len)
+        compiled = step.lower(
+            _on(model.param_shapes(), param_sh), _on(cache_spec, cache_sh),
+            jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=tok_sh),
+        ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    cfg = model.cfg
+    widened = batch * max_len * cfg.num_heads * cfg.resolved_head_dim
+    shapes = set(re.findall(r"\w+\[([\d,]+)\]", compiled.as_text()))
+    assert not [s for s in shapes
+                if math.prod(int(n) for n in s.split(",")) == widened]
 
 
 def test_chip_smoke_phases_on_cpu():

@@ -18,10 +18,12 @@ from .base import (  # noqa: F401
     SHAPES,
     ShapeConfig,
     XLSTMConfig,
+    YaRNConfig,
 )
 
 _MODULES: Dict[str, str] = {
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "deepseek-v3-671b": "deepseek_v3_671b",
     "glm4-9b": "glm4_9b",
     "codeqwen1.5-7b": "codeqwen15_7b",

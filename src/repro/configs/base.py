@@ -29,6 +29,28 @@ class MoEConfig:
     #   ep2d   — experts on `data`×`model` jointly (pure EP at E ≥ chips:
     #            weights never move; tokens all-to-all to expert owners)
     expert_sharding: str = "fsdp_d"
+    # The routed experts this chip holds, [first_held, first_held + num_held)
+    # of the num_experts the router scores (a chip's share under expert
+    # parallelism); 0 holds them all.  Read on a mesh whose ``model`` axis
+    # is 1, where the layer is the dropless expert-share layer.
+    num_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 applies it."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -74,6 +96,7 @@ class ModelConfig:
     rglru: Optional[RGLRUConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     rope_theta: float = 500000.0
+    yarn: Optional[YaRNConfig] = None  # None → plain rotary
     act: str = "swiglu"          # swiglu | gelu
     causal: bool = True          # False → encoder-only (no decode path)
     tie_embeddings: bool = False
@@ -100,7 +123,20 @@ class ModelConfig:
         return self.num_layers // p, self.num_layers % p
 
     def with_overrides(self, **kw) -> "ModelConfig":
+        """A copy with ``kw`` replaced.  A dict given for a nested group
+        (``moe``, ``mla``, ``yarn``, ...) is merged over this config's
+        group, or builds the group where this config has none."""
+        for k, v in kw.items():
+            if isinstance(v, dict):
+                v = {f: tuple(x) if isinstance(x, list) else x
+                     for f, x in v.items()}
+                base = getattr(self, k)
+                kw[k] = replace(base, **v) if base is not None else _GROUPS[k](**v)
         return replace(self, **kw)
+
+
+_GROUPS = {"moe": MoEConfig, "mla": MLAConfig, "rglru": RGLRUConfig,
+           "xlstm": XLSTMConfig, "yarn": YaRNConfig}
 
 
 @dataclass(frozen=True)

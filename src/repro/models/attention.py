@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import MLAConfig, ModelConfig
-from .layers import ashard, rmsnorm, rmsnorm_spec, rope
+from .layers import ashard, rmsnorm, rmsnorm_spec, rope, yarn_mscale
 from .specs import ParamSpec
 
 _NEG_INF = -1e30
@@ -248,8 +248,9 @@ def _project_qkv(p, x, cfg: ModelConfig, positions):
     q = (x @ p["wq"]).reshape(B, T, H, hd)
     k = (x @ p["wk"]).reshape(B, T, K, hd)
     v = (x @ p["wv"]).reshape(B, T, K, hd)
-    q = ashard(rope(q, positions, cfg.rope_theta), ("batch", None, "heads", None))
-    k = rope(k, positions, cfg.rope_theta)
+    q = ashard(rope(q, positions, cfg.rope_theta, cfg.yarn),
+               ("batch", None, "heads", None))
+    k = rope(k, positions, cfg.rope_theta, cfg.yarn)
     return q, k, v
 
 
@@ -308,8 +309,8 @@ def gqa_decode(p, x, cfg: ModelConfig, cache: KVCache):
     k = (x @ p["wk"]).reshape(B, 1, K, hd)
     v = (x @ p["wv"]).reshape(B, 1, K, hd)
     ppos = jnp.full((B, 1), pos, jnp.int32)
-    q = rope(q, ppos, cfg.rope_theta)
-    k = rope(k, ppos, cfg.rope_theta)
+    q = rope(q, ppos, cfg.rope_theta, cfg.yarn)
+    k = rope(k, ppos, cfg.rope_theta, cfg.yarn)
     S = cache.k.shape[1]
     slot = jnp.where(cfg.window > 0, pos % S, jnp.minimum(pos, S - 1))
     ck = jax.lax.dynamic_update_slice(cache.k, k, (0, slot, 0, 0))
@@ -375,7 +376,7 @@ def _mla_q(p, x, cfg: ModelConfig, positions):
     else:
         q = jnp.einsum("btd,dhe->bthe", x, p["wq"])
     q_nope, q_rope = q[..., : m.nope_head_dim], q[..., m.nope_head_dim :]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
     return ashard(q_nope, ("batch", None, "heads", None)), ashard(
         q_rope, ("batch", None, "heads", None)
     )
@@ -383,8 +384,19 @@ def _mla_q(p, x, cfg: ModelConfig, positions):
 
 def _mla_latents(p, x, cfg: ModelConfig, positions):
     c_kv = rmsnorm(p["kv_norm"], x @ p["w_dkv"])            # [B, T, r]
-    k_rope = rope((x @ p["w_kr"])[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = rope((x @ p["w_kr"])[:, :, None, :], positions, cfg.rope_theta,
+                  cfg.yarn)[:, :, 0]
     return c_kv, k_rope
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(nope + rope head dims), times YaRN's mscale² where the
+    model sets ``mscale_all_dim`` (DeepSeek-V2)."""
+    m, y = cfg.mla, cfg.yarn
+    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_attention(p, x, cfg: ModelConfig, *, use_pallas: bool = False):
@@ -404,7 +416,6 @@ def mla_attention(p, x, cfg: ModelConfig, *, use_pallas: bool = False):
         axis=-1,
     )
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
     attend = online_attention
     if use_pallas:
         from ..kernels import ops as kops
@@ -412,7 +423,7 @@ def mla_attention(p, x, cfg: ModelConfig, *, use_pallas: bool = False):
         attend = kops.flash_attention
     out = attend(
         q, k, v, causal=cfg.causal, window=cfg.window,
-        q_block=cfg.q_block, k_block=cfg.k_block, scale=scale,
+        q_block=cfg.q_block, k_block=cfg.k_block, scale=mla_softmax_scale(cfg),
     )
     y = out.reshape(B, T, -1) @ p["wo"]
     return ashard(y, ("batch", None, "embed"))
@@ -439,7 +450,6 @@ def mla_decode(p, x, cfg: ModelConfig, cache: MLACache):
     q_lat = q_nope · W_uk  →  scores = q_lat · c_kv + q_rope · k_rope
     out   = (attn · c_kv) · W_uv — the cache stays compressed end-to-end.
     """
-    m = cfg.mla
     B = x.shape[0]
     pos = cache.length
     ppos = jnp.full((B, 1), pos, jnp.int32)
@@ -448,17 +458,17 @@ def mla_decode(p, x, cfg: ModelConfig, cache: MLACache):
     c_kv = jax.lax.dynamic_update_slice(cache.c_kv, c_new, (0, pos, 0))
     k_rope = jax.lax.dynamic_update_slice(cache.k_rope, kr_new, (0, pos, 0))
 
-    q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, p["w_uk"])  # absorb W_uk
-    s_lat = jnp.einsum("bthr,bsr->bths", q_lat, c_kv)
-    s_rope = jnp.einsum("bthd,bsd->bths", q_rope, k_rope)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
-    s = (s_lat + s_rope).astype(jnp.float32) * scale
-    S = c_kv.shape[1]
-    valid = jnp.arange(S)[None, :] < (pos + 1)
-    s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-    a = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("bths,bsr->bthr", a, c_kv)            # reduce in latent
-    out = jnp.einsum("bthr,rhd->bthd", o_lat, p["w_uv"])     # absorb W_uv
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, p["w_uk"])  # absorb W_uk
+        s_lat = jnp.einsum("bthr,bsr->bths", q_lat, c_kv)
+        s_rope = jnp.einsum("bthd,bsd->bths", q_rope, k_rope)
+        s = (s_lat + s_rope).astype(jnp.float32) * mla_softmax_scale(cfg)
+        S = c_kv.shape[1]
+        valid = jnp.arange(S)[None, :] < (pos + 1)
+        s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
+        a = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        o_lat = jnp.einsum("bths,bsr->bthr", a, c_kv)        # reduce in latent
+        out = jnp.einsum("bthr,rhd->bthd", o_lat, p["w_uv"])  # absorb W_uv
     y = out.reshape(B, 1, -1) @ p["wo"]
     new_cache = MLACache(c_kv=c_kv, k_rope=k_rope, length=cache.length + 1)
     return ashard(y, ("batch", None, "embed")), new_cache

@@ -8,10 +8,12 @@ dry-run's ShapeDtypeStruct lowering, and the Pallas-kernel swap.
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .specs import ParamSpec
@@ -133,17 +135,49 @@ def unembed(p, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # -------------------------------------------------------------------- RoPE --
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding, half-split convention.
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1·mscale·ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_freqs(d: int, theta: float, yarn) -> np.ndarray:
+    """DeepSeek-V2's YaRN frequencies for a rotary dim ``d``: the original
+    frequencies for the fast dims, those divided by ``factor`` for the slow
+    ones, and a linear ramp between the correction dims of ``beta_fast``
+    and ``beta_slow`` rotations over the original context."""
+    def corr_dim(rot):
+        return (d * math.log(yarn.original_max_position_embeddings
+                             / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), d - 1)
+    extra = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float32) / d)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                   / ((high - low) or 0.001), 0, 1)
+    return (extra / yarn.factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         yarn=None) -> jnp.ndarray:
+    """Rotary embedding, half-split convention; ``yarn`` (a
+    ``YaRNConfig``) blends in YaRN's frequencies and scales cos and sin
+    by its ``mscale / mscale_all_dim`` ratio.
 
     x: [..., T, H, d] (d even); positions: broadcastable to [..., T].
     """
     d = x.shape[-1]
     half = d // 2
-    freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        freq = jnp.asarray(_yarn_freqs(d, theta, yarn))
     ang = positions.astype(jnp.float32)[..., None] * freq  # [..., T, half]
     cos = jnp.cos(ang)[..., None, :]  # broadcast over heads
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

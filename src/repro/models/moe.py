@@ -14,15 +14,32 @@ einsum/scatter (TPU-friendly, differentiable, GSPMD-shardable).  Per-chip
 capacity memory is ``E·C_g·D / |model|`` — bounded regardless of global
 batch.  A dense one-hot path (`dispatch="onehot"`) is kept as the numerical
 oracle in tests (groups=1).
+
+On a mesh of one device (a serving chip, or no mesh at all) the layer is
+the dropless *expert-share* layer instead: the chip holds the
+routed experts ``MoEConfig.first_held`` .. ``+ num_held`` of the
+``num_experts`` its router scores, sorts the (token, choice) pairs whose
+expert it holds by expert, and runs each held expert over exactly its rows
+as a grouped product (``lax.ragged_dot``); a decode step's few tokens run
+through every held expert instead, and their gates pick the results
+(``DENSE_TOKENS``).  No capacity, so no token is dropped at any batch or
+imbalance, and a prefill then decode agrees with a full forward.  The
+pairs routed to experts held elsewhere are left out: under expert
+parallelism their part of the result comes from other chips.  Training on
+one device takes this layer too; meshes of more devices keep the capacity
+paths.
 """
 
 from __future__ import annotations
 
+import math
+import time
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ModelConfig, MoEConfig
 from .layers import ashard, mlp, mlp_spec
 from .specs import ParamSpec
@@ -51,9 +68,9 @@ def moe_spec(cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict:
         "router": ParamSpec(
             (D, E), ("embed", None), init="normal", scale=0.006, dtype=jnp.float32
         ),
-        # Fused gate+up per expert.
-        "wi": ParamSpec((E, D, 2 * F), wi_l, dtype=dtype),
-        "wo": ParamSpec((E, F, D), wo_l, dtype=dtype),
+        # Fused gate+up per held expert; the router scores all E.
+        "wi": ParamSpec((m.held, D, 2 * F), wi_l, dtype=dtype),
+        "wo": ParamSpec((m.held, F, D), wo_l, dtype=dtype),
     }
     if m.num_shared:
         spec["shared"] = mlp_spec(D, m.num_shared * F, "swiglu", dtype)
@@ -136,6 +153,63 @@ def _scatter_moe(p, xg: jnp.ndarray, m: MoEConfig) -> Tuple[jnp.ndarray, jnp.nda
     picked = jnp.take_along_axis(ye, slot[..., None], axis=1)  # [G, S*k, D]
     picked = picked * gates.reshape(G, S * k, 1).astype(ye.dtype)
     y = jnp.zeros((G, S, D), ye.dtype).at[garange, token_of[None, :]].add(picked)
+    return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Expert share (dropless; one chip's experts, grouped products)
+# ---------------------------------------------------------------------------
+# Up to this many tokens (a decode step's batch) every held expert runs
+# over every token and the gates pick the results: the step is bound by
+# reading the held experts' weights either way (a v5e's ridge is ~240 rows
+# of bf16 weights), and a plain product reads each layer's weights in place
+# from the scanned stack, where XLA copies them out for the grouped
+# product's custom call first.  Above it (prefill), the grouped products
+# compute only the rows each expert has.
+DENSE_TOKENS = 256
+
+
+def _share_moe(p, x: jnp.ndarray, m: MoEConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x: [S, D] → (y [S, D], aux): the held experts' part of the routed
+    result for every token, with no capacity and no drops."""
+    S, D = x.shape
+    E, k, n = m.num_experts, m.top_k, m.held
+    t = time.perf_counter_ns()
+    obs.record("moe.plan", t, t, experts_held=n, experts_routed=E, top_k=k,
+               dropless=True)
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = _router_probs(logits, m)
+        gates, idx = _topk_gates(probs, m)                  # [S, k]
+        counts = jnp.zeros((E,), jnp.int32).at[idx.reshape(-1)].add(1)
+        aux = aux_load_balance_loss(probs[None], counts[None], m)
+        local = idx - m.first_held
+        mine = (local >= 0) & (local < n)                   # [S, k]
+    if S <= DENSE_TOKENS:
+        with jax.named_scope("moe.route"):
+            w = jnp.sum(jnp.where(local[..., None] == jnp.arange(n),
+                                  gates[..., None], 0.0), axis=1)  # [S, n]
+        with jax.named_scope("moe.experts"):
+            h = jnp.einsum("sd,edf->esf", x, p["wi"])
+            gate_h, up_h = jnp.split(h, 2, axis=-1)
+            out = jnp.einsum("esf,efd->esd", jax.nn.silu(gate_h) * up_h, p["wo"])
+            y = jnp.sum(w.T[..., None] * out.astype(jnp.float32), axis=0)
+        return y.astype(x.dtype), aux
+    with jax.named_scope("moe.route"):
+        # Pairs sorted by held expert; those held elsewhere sort last and
+        # lie past the groups, where the grouped products do not reach.
+        order = jnp.argsort(jnp.where(mine, local, n).reshape(-1), stable=True)
+        sizes = counts[m.first_held:m.first_held + n]
+        rows = x[order // k]                                # [S*k, D]
+    with jax.named_scope("moe.experts"):
+        h = jax.lax.ragged_dot(rows, p["wi"], sizes)
+        gate_h, up_h = jnp.split(h, 2, axis=-1)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate_h) * up_h, p["wo"], sizes)
+    with jax.named_scope("moe.route"):
+        pairs = out[jnp.argsort(order)].reshape(S, k, D)    # back to [S, k]
+        pairs = jnp.where(mine[..., None], pairs.astype(jnp.float32), 0.0)
+        y = jnp.sum(pairs * gates[..., None], axis=1).astype(x.dtype)
     return y, aux
 
 
@@ -299,21 +373,27 @@ def _manual_ep_moe(p, x: jnp.ndarray, cfg: ModelConfig):
 def moe_ffn(
     p, x: jnp.ndarray, cfg: ModelConfig, dispatch: str = "scatter"
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """MoE FFN. x: [B, T, D] → (y [B, T, D], aux scalar)."""
+    """MoE FFN. x: [B, T, D] → (y [B, T, D], aux scalar).
+
+    On a mesh of one device the expert-share layer; else the capacity
+    paths of the sharded layouts.  ``dispatch="onehot"`` is the capacity
+    oracle of the tests."""
     m = cfg.moe
     B, T, D = x.shape
     S = B * T
-    use_island = m.expert_sharding == "ep_a2a" and dispatch == "scatter"
-    if use_island:
+    axes = dict(jax.sharding.get_abstract_mesh().shape)
+    msize = axes.get("model", 1)
+    if dispatch == "scatter" and math.prod(axes.values()) == 1:
+        y, aux = _share_moe(p, x.reshape(S, D), m)
+        y = y.reshape(B, T, D)
+    elif m.held != m.num_experts:
+        raise ValueError("a share of the experts is served on one device")
+    elif m.expert_sharding == "ep_a2a" and dispatch == "scatter" \
+            and msize > 1 and T % msize == 0:
         # The island slices T over `model` to dedup the replicated batch;
         # decode (T=1) and ragged T fall back to the GSPMD scatter path
         # (small tensors — the expensive case the island exists for is the
         # capacity-buffer einsum at training/prefill scale).
-        mesh = jax.sharding.get_abstract_mesh()
-        msize = dict(mesh.shape).get("model", 1) if mesh is not None else 1
-        if T % max(msize, 1) != 0 or msize <= 1:
-            use_island = False
-    if use_island:
         y, aux = _manual_ep_moe(p, x, cfg)
     else:
         G = m.groups if (m.groups >= 1 and S % m.groups == 0) else 1
@@ -324,5 +404,6 @@ def moe_ffn(
         yg, aux = fn(p, xg, m)
         y = yg.reshape(B, T, D)
     if m.num_shared:
-        y = y + mlp(p["shared"], x, "swiglu")
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(p["shared"], x, "swiglu")
     return ashard(y, ("batch", None, "embed")), aux

@@ -47,11 +47,13 @@ def test_online_attention_equals_reference(T, H, K, d, qb, kb, causal, seed):
 )
 def test_moe_dispatch_conservation(S, E, k, seed):
     """Every token contributes ≤ k expert slots; outputs are finite; the
-    scatter path equals the one-hot oracle whenever capacity suffices."""
+    scatter path (the capacity path of sharded meshes) equals the one-hot
+    oracle whenever capacity suffices, and so does the expert-share layer
+    that a one-device mesh takes."""
     import dataclasses
 
     from repro.configs.base import ModelConfig, MoEConfig
-    from repro.models.moe import moe_ffn, moe_spec
+    from repro.models.moe import _onehot_moe, _scatter_moe, moe_ffn, moe_spec
     from repro.models.specs import init_params
 
     cfg = ModelConfig(
@@ -62,10 +64,13 @@ def test_moe_dispatch_conservation(S, E, k, seed):
     )
     params = init_params(moe_spec(cfg, jnp.float32), jax.random.PRNGKey(seed))
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, S, 16), jnp.float32)
-    y1, a1 = moe_ffn(params, x, cfg, dispatch="scatter")
-    y2, a2 = moe_ffn(params, x, cfg, dispatch="onehot")
+    y1, a1 = _scatter_moe(params, x, cfg.moe)
+    y2, a2 = _onehot_moe(params, x, cfg.moe)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-4)
     assert bool(jnp.isfinite(a1)) and bool(jnp.all(jnp.isfinite(y1)))
+    y3, a3 = moe_ffn(params, x, cfg, dispatch="scatter")
+    np.testing.assert_allclose(np.asarray(y3), np.asarray(y2), atol=1e-4)
+    assert bool(jnp.isfinite(a3))
 
 
 @settings(max_examples=20, deadline=None)

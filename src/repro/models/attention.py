@@ -20,6 +20,12 @@ that head's G = H/K queries as the rows of each matmul, and puts the
 expanded form on the 16×16 mesh, that cuts decode_32k's ICI bytes a chip
 threefold for glm4-9b and tenfold for llama3-8b.
 
+Decode reads a layer's cache and never writes it: the new token's own key
+and value join the softmax beside the cache, and the step returns them as
+one-slot rows that `write_rows` puts in place after the layer scan, so a
+step writes one row a layer rather than passing each layer's whole cache
+through the scan.
+
 MLA (DeepSeek multi-head latent attention) keeps the compressed KV cache
 ``(c_kv, k_rope)`` — 576 floats/token instead of 2·H·d — and uses the
 *absorbed-weight* decode path (scores and values computed in the latent
@@ -165,34 +171,75 @@ def decode_attention(
     q: jnp.ndarray,          # [B, 1, H, dk]
     k_cache: jnp.ndarray,    # [B, S, K, dk]
     v_cache: jnp.ndarray,    # [B, S, K, dv]
-    length: jnp.ndarray,     # [B] or scalar — #valid cache entries
+    valid: jnp.ndarray,      # [B, S] or [S] bool — cache slots to read
+    k_new: jnp.ndarray,      # [B, 1, K, dk]
+    v_new: jnp.ndarray,      # [B, 1, K, dv]
     *,
-    window: int = 0,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """One query row per sequence against its first ``length`` cache slots
-    (the last ``window`` of them if ``window > 0``), per KV head: each head's
-    G = H/K query heads are the rows of the score and value matmuls, so the
-    cache is read once, as stored."""
+    """One query row per sequence against its ``valid`` cache slots and its
+    own token's key and value, which the cache does not hold yet, in one
+    softmax; per KV head: each head's G = H/K query heads are the rows of
+    the score and value matmuls, so the cache is read once, as stored."""
     B, S, K, dk = k_cache.shape
     H = q.shape[2]
     model = jax.sharding.get_abstract_mesh().shape.get("model", 0)
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
-    pos = jnp.arange(S)[None, :]
-    lb = jnp.broadcast_to(jnp.asarray(length).reshape(-1, 1), (B, S))
-    valid = pos < lb
-    if window > 0:
-        valid &= pos >= lb - window
+    valid = jnp.broadcast_to(valid, (B, S))
     qg = ashard(q[:, 0].reshape(B, K, H // K, dk),
                 _grouped_axes(kv_model_dim(K, dk, model)))
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, v_cache,
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new[:, 0],
+                       preferred_element_type=jnp.float32) * scale
+    p, p_new = _softmax_with_new(s, s_new)
+    dt = v_cache.dtype
+    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(dt), v_cache,
                      preferred_element_type=jnp.float32)
+    out = out + (p_new.astype(dt).astype(jnp.float32)[..., None]
+                 * v_new[:, 0, :, None, :].astype(jnp.float32))
     out = ashard(out, _grouped_axes(kv_model_dim(K, v_cache.shape[-1], model)))
-    return out.reshape(B, 1, H, -1).astype(v_cache.dtype)
+    return out.reshape(B, 1, H, -1).astype(dt)
+
+
+def _softmax_with_new(s: jnp.ndarray, s_new: jnp.ndarray):
+    """Softmax over one row of S + 1 scores held apart: the cache's ``s``
+    [..., S] and the new token's ``s_new`` [...] share one max and one
+    denominator.  Returns both parts' probabilities in f32."""
+    m = jnp.maximum(jnp.max(s, axis=-1), s_new)
+    p = jnp.exp(s - m[..., None])
+    p_new = jnp.exp(s_new - m)
+    denom = jnp.sum(p, axis=-1) + p_new
+    return p / denom[..., None], p_new / denom
+
+
+def decode_slot(cfg: ModelConfig, pos: jnp.ndarray, S: int) -> jnp.ndarray:
+    """The cache slot that the token at ``pos`` takes: ``pos % S`` in a
+    windowed ring buffer, else ``pos``, held at the last slot once the
+    cache is full."""
+    return pos % S if cfg.window > 0 else jnp.minimum(pos, S - 1)
+
+
+def cached_slots(cfg: ModelConfig, pos: jnp.ndarray, S: int) -> jnp.ndarray:
+    """[S] bool: the cache slots that the token at ``pos`` attends besides
+    itself — those written before it, less the one its own row replaces."""
+    i = jnp.arange(S)
+    return (i < jnp.minimum(pos, S)) & (i != decode_slot(cfg, pos, S))
+
+
+def write_rows(cfg: ModelConfig, cache, rows):
+    """A decode step's ``rows`` (what `gqa_decode` and `mla_decode` return:
+    the new token's entries with one slot, ``length`` advanced) written
+    into ``cache`` at the token's slot, one update per array.  A stacked
+    cache ([L, B, S, ...], ``length`` [L]) takes all L layers' rows at
+    once; every layer holds the same length."""
+    axis = cache.length.ndim + 1
+    pos = cache.length.reshape(-1)[0]
+    slot = decode_slot(cfg, pos, cache[0].shape[axis])
+    arrays = [jax.lax.dynamic_update_slice_in_dim(c, r, slot, axis)
+              for c, r in zip(cache[:-1], rows[:-1])]
+    return type(cache)(*arrays, rows.length)
 
 
 def kv_model_dim(num_kv_heads: int, head_dim: int, model_size: int) -> int:
@@ -301,7 +348,9 @@ def gqa_prefill(p, x, cfg: ModelConfig, max_len: int):
 
 
 def gqa_decode(p, x, cfg: ModelConfig, cache: KVCache):
-    """One decode step. x: [B, 1, D]; returns ([B, 1, D], new cache)."""
+    """One decode step against a cache it only reads. x: [B, 1, D];
+    returns ([B, 1, D], the new token's K and V rows as a one-slot
+    `KVCache` with ``length + 1``), for `write_rows`."""
     B, _, D = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     pos = cache.length  # absolute position of the new token
@@ -311,18 +360,11 @@ def gqa_decode(p, x, cfg: ModelConfig, cache: KVCache):
     ppos = jnp.full((B, 1), pos, jnp.int32)
     q = rope(q, ppos, cfg.rope_theta, cfg.yarn)
     k = rope(k, ppos, cfg.rope_theta, cfg.yarn)
-    S = cache.k.shape[1]
-    slot = jnp.where(cfg.window > 0, pos % S, jnp.minimum(pos, S - 1))
-    ck = jax.lax.dynamic_update_slice(cache.k, k, (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache.v, v, (0, slot, 0, 0))
-    if cfg.window > 0:
-        n_valid = jnp.minimum(pos + 1, S)
-        out = decode_attention(q, ck, cv, jnp.broadcast_to(n_valid, (B,)))
-    else:
-        out = decode_attention(q, ck, cv, jnp.broadcast_to(pos + 1, (B,)))
+    valid = cached_slots(cfg, pos, cache.k.shape[1])
+    out = decode_attention(q, cache.k, cache.v, valid, k, v)
     y = out.reshape(B, 1, -1) @ p["wo"]
-    new_cache = KVCache(k=ck, v=cv, length=cache.length + 1)
-    return ashard(y, ("batch", None, "embed")), new_cache
+    rows = KVCache(k=k, v=v, length=cache.length + 1)
+    return ashard(y, ("batch", None, "embed")), rows
 
 
 # ---------------------------------------------------------------------------
@@ -449,26 +491,34 @@ def mla_decode(p, x, cfg: ModelConfig, cache: MLACache):
 
     q_lat = q_nope · W_uk  →  scores = q_lat · c_kv + q_rope · k_rope
     out   = (attn · c_kv) · W_uv — the cache stays compressed end-to-end.
+    The cache is only read: the new token's latent row and rope key join
+    the softmax beside it, and are returned as a one-slot `MLACache` with
+    ``length + 1``, for `write_rows`.
     """
     B = x.shape[0]
     pos = cache.length
     ppos = jnp.full((B, 1), pos, jnp.int32)
     q_nope, q_rope = _mla_q(p, x, cfg, ppos)
     c_new, kr_new = _mla_latents(p, x, cfg, ppos)
-    c_kv = jax.lax.dynamic_update_slice(cache.c_kv, c_new, (0, pos, 0))
-    k_rope = jax.lax.dynamic_update_slice(cache.k_rope, kr_new, (0, pos, 0))
+    c_kv, k_rope = cache.c_kv, cache.k_rope
 
     with jax.named_scope("mla.absorb"):
         q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, p["w_uk"])  # absorb W_uk
         s_lat = jnp.einsum("bthr,bsr->bths", q_lat, c_kv)
         s_rope = jnp.einsum("bthd,bsd->bths", q_rope, k_rope)
         s = (s_lat + s_rope).astype(jnp.float32) * mla_softmax_scale(cfg)
-        S = c_kv.shape[1]
-        valid = jnp.arange(S)[None, :] < (pos + 1)
-        s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-        a = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        o_lat = jnp.einsum("bths,bsr->bthr", a, c_kv)        # reduce in latent
-        out = jnp.einsum("bthr,rhd->bthd", o_lat, p["w_uv"])  # absorb W_uv
+        s_new = (jnp.einsum("bthr,btr->bth", q_lat, c_new)
+                 + jnp.einsum("bthd,btd->bth", q_rope, kr_new))
+        s_new = s_new.astype(jnp.float32) * mla_softmax_scale(cfg)
+        valid = cached_slots(cfg, pos, c_kv.shape[1])
+        s = jnp.where(valid[None, None, None, :], s, _NEG_INF)
+        a, a_new = _softmax_with_new(s, s_new)
+        o_lat = jnp.einsum("bths,bsr->bthr", a.astype(x.dtype), c_kv,
+                           preferred_element_type=jnp.float32)
+        o_lat = o_lat + (a_new.astype(x.dtype).astype(jnp.float32)[..., None]
+                         * c_new[:, :, None, :].astype(jnp.float32))
+        out = jnp.einsum("bthr,rhd->bthd", o_lat.astype(x.dtype),
+                         p["w_uv"])                             # absorb W_uv
     y = out.reshape(B, 1, -1) @ p["wo"]
-    new_cache = MLACache(c_kv=c_kv, k_rope=k_rope, length=cache.length + 1)
-    return ashard(y, ("batch", None, "embed")), new_cache
+    rows = MLACache(c_kv=c_new, k_rope=kr_new, length=cache.length + 1)
+    return ashard(y, ("batch", None, "embed")), rows

@@ -23,11 +23,13 @@ Three entry points per model, matching the dry-run cells:
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ModelConfig
 from . import attention as attn
 from . import moe as moe_mod
@@ -190,6 +192,11 @@ def _block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     raise ValueError(kind)
 
 
+#: Caches that decode extends by one row a step (`attn.write_rows`); every
+#: other cache is a recurrent state that a step replaces whole.
+_ROW_CACHES = (attn.KVCache, attn.MLACache)
+
+
 # ---------------------------------------------------------------------------
 # Layer grouping
 # ---------------------------------------------------------------------------
@@ -324,7 +331,37 @@ class Model:
             if caches is not None
             else None
         )
+        if mode == "decode":
+            new_caches = self._write_decode(caches, new_caches)
         return x, new_caches, aux_total
+
+    def _write_decode(self, caches, new):
+        """After a decode stack: each KV or latent cache, read-only inside
+        the scan, takes its layers' new rows at the step's slot
+        (`attn.write_rows`: one update per array, in the donated buffer);
+        a recurrent state is replaced whole.  Records ``decode.cache_write``
+        with the layers of each kind and the bytes of rows a step writes."""
+        counts = dict(row_layers=0, state_layers=0, row_bytes_per_step=0)
+
+        def put(c, n, layers):
+            if not isinstance(c, _ROW_CACHES):
+                counts["state_layers"] += layers
+                return n
+            counts["row_layers"] += layers
+            counts["row_bytes_per_step"] += sum(a.size * a.dtype.itemsize
+                                                for a in n[:-1])
+            return attn.write_rows(self.cfg, c, n)
+
+        out = {
+            "lead": [put(c, n, 1) for c, n in zip(caches["lead"], new["lead"])],
+            "blocks": None if caches["blocks"] is None else {
+                k: put(c, new["blocks"][k], self.plan.n_scan)
+                for k, c in caches["blocks"].items()},
+            "tail": [put(c, n, 1) for c, n in zip(caches["tail"], new["tail"])],
+        }
+        t = time.perf_counter_ns()
+        obs.record("decode.cache_write", t, t, **counts)
+        return out
 
     def forward(self, params, batch) -> jnp.ndarray:
         """Training-mode forward to final hidden states [B, T, D]."""

@@ -6,8 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import ARCHS, ShapeConfig, get_config
-from repro.models import Model, input_specs
+from repro.models import Model, attention as attn, input_specs
 
 SHAPE = ShapeConfig("smoke", seq_len=32, global_batch=2, kind="train")
 
@@ -38,14 +39,24 @@ def test_forward_hidden_shape(arch):
     assert bool(jnp.all(jnp.isfinite(h.astype(jnp.float32))))
 
 
+CAUSAL = [a for a in ARCHS if get_config(a).causal]
+
+
 @pytest.mark.parametrize(
-    "arch", [a for a in ARCHS if get_config(a).causal]
+    "arch,T,steps",
+    [pytest.param(a, 24, 1, id=a) for a in CAUSAL]
+    + [pytest.param(a, 14, 4, id=f"{a}-4steps") for a in CAUSAL],
 )
-def test_prefill_decode_matches_forward(arch):
+def test_prefill_decode_matches_forward(arch, T, steps):
+    """Prefill's last logits, then each decode step's, equal ``forward``
+    over the prompt so far, and afterwards every KV or latent cache holds
+    what a prefill over the same tokens writes.  Recurrentgemma's 16-slot
+    ring buffer has wrapped in a 24-token prefill, and wraps during decode
+    after a 14-token one."""
     cfg = get_config(arch, smoke=True).with_overrides(dtype="float32")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    T, B, max_len = 24, 2, 32
+    B, max_len = 2, 32
     batch = input_specs(cfg, ShapeConfig("p", T, B, "prefill"), concrete=True,
                         dtype=jnp.float32)
     logits_p, cache = model.prefill(params, batch, max_len)
@@ -55,14 +66,57 @@ def test_prefill_decode_matches_forward(arch):
     np.testing.assert_allclose(np.asarray(logits_p), np.asarray(ref_p),
                                atol=2e-4, rtol=1e-3)
 
-    tok = jax.random.randint(jax.random.PRNGKey(9), (B, 1), 0, cfg.vocab_size)
-    logits_d, cache = model.decode_step(params, cache, tok)
-    batch2 = dict(batch)
-    batch2["tokens"] = jnp.concatenate([batch["tokens"], tok], axis=1)
-    h, _ = model.forward(params, batch2)
-    ref_d = model._logits(params, h)[:, -1:]
-    np.testing.assert_allclose(np.asarray(logits_d), np.asarray(ref_d),
-                               atol=5e-3, rtol=1e-2)
+    decode = jax.jit(model.decode_step)
+    toks = jax.random.randint(jax.random.PRNGKey(9), (B, steps), 0,
+                              cfg.vocab_size)
+    grown = dict(batch)
+    for i in range(steps):
+        logits_d, cache = decode(params, cache, toks[:, i:i + 1])
+        grown["tokens"] = jnp.concatenate([batch["tokens"], toks[:, :i + 1]],
+                                          axis=1)
+        h, _ = model.forward(params, grown)
+        ref_d = model._logits(params, h)[:, -1:]
+        np.testing.assert_allclose(np.asarray(logits_d), np.asarray(ref_d),
+                                   atol=5e-3, rtol=1e-2, err_msg=f"step {i}")
+
+    _, want = model.prefill(params, grown, max_len)
+    layers = lambda c: [*c["lead"], *(c["blocks"] or {}).values(), *c["tail"]]
+    pairs = [(c, w) for c, w in zip(layers(cache), layers(want))
+             if isinstance(c, (attn.KVCache, attn.MLACache))]
+    assert bool(pairs) == ("attn" in cfg.block_pattern)
+    for got, ref in pairs:
+        assert np.all(np.asarray(got.length) == T + steps)
+        for g, r in zip(got[:-1], ref[:-1]):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       atol=1e-4, rtol=1e-4)
+
+
+def test_decode_cache_write_record():
+    """``decode.cache_write`` counts the layers whose cache takes one row a
+    step and those whose state a step replaces: DeepSeek-V2-Lite's chip
+    share (27 latent caches: the dense first layer and 26 scanned, 576
+    bf16 values a row, batch 32) at trace time, with no arrays made."""
+    def record(cfg, batch, max_len):
+        model = Model(cfg)
+        obs.reset()
+        jax.eval_shape(model.decode_step, model.param_shapes(),
+                       model.cache(batch, max_len, as_spec=True),
+                       jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+        (rec,) = obs.spans("decode.cache_write")
+        return rec.attrs
+
+    share = get_config("deepseek-v2-lite").with_overrides(moe={"num_held": 16})
+    assert record(share, 32, 1280) == {
+        "row_layers": 27, "state_layers": 0,
+        "row_bytes_per_step": 27 * 32 * (512 + 64) * 2}
+    # recurrentgemma's smoke stack: rec, rec, attn scanned once, then two
+    # rec tail layers; a row is K and V of one 16-wide KV head in bf16.
+    assert record(get_config("recurrentgemma-9b", smoke=True), 2, 32) == {
+        "row_layers": 1, "state_layers": 4,
+        "row_bytes_per_step": 2 * 2 * 16 * 2}
+    xl = record(get_config("xlstm-1.3b", smoke=True), 2, 32)
+    assert xl["row_layers"] == 0 and xl["row_bytes_per_step"] == 0
+    assert xl["state_layers"] == 4
 
 
 def test_encoder_has_no_decode_shapes():
